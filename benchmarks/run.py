@@ -105,6 +105,9 @@ def main() -> None:
                     help="comma-separated bench names (e.g. roofline,kernels)")
     args, _ = ap.parse_known_args()
 
+    from repro.serving.compile_cache import enable_jax_compilation_cache
+    enable_jax_compilation_cache()
+
     if args.smoke:
         sys.exit(smoke())
 
@@ -186,14 +189,17 @@ def main() -> None:
     }
     selected = (args.only.split(",") if args.only else list(benches))
     print("name,us_per_call,derived")
+    failed = 0
     for name in selected:
         t0 = time.time()
         try:
             benches[name].run(full=args.full)
             print(f"bench/{name}/wall_s,{(time.time()-t0)*1e6:.0f},ok")
-        except Exception as e:  # keep the harness running
+        except Exception as e:  # run the rest, then exit non-zero
+            failed += 1
             print(f"bench/{name}/ERROR,0,{type(e).__name__}: "
                   f"{str(e)[:160]}")
+    sys.exit(1 if failed else 0)
 
 
 if __name__ == '__main__':

@@ -10,9 +10,10 @@ rules the kernels enforce at dispatch:
     alias already-enumerated points under a different name);
   * ``hoist_reuse > 1`` requires the hoist; pipeline mode implies it
     (``KernelSchedule.__post_init__``); ``ii`` is a pipeline-only axis;
-  * ``backend="pallas_tpu"`` points must pass ``ops.check_tpu_alignment``
-    (128-lane column tiles, 8-sublane batch tiles) — misaligned points are
-    pruned, not clamped, because they would raise at dispatch;
+  * points that run compiled Pallas (``"pallas_tpu"``, or ``"auto"`` on a
+    TPU) must pass ``ops.check_tpu_alignment`` (whole-width or 128-lane
+    column tiles, 8-sublane batch tiles) — misaligned points are pruned,
+    not clamped, because they would raise at dispatch;
   * duplicates (same ``schedule.key()``) collapse to one point.
 
 The result is deterministic (sorted by key) so Pareto frontiers and selected
@@ -48,9 +49,9 @@ class SpaceSpec:
     """Which slice of the schedule space to enumerate.
 
     ``reuse_factors=None`` means every divisor of the gate dimension — the
-    full hls4ml-legal R axis.  The defaults describe the container-friendly
-    slice (interpret backend, one block_batch); hardware sweeps pass
-    ``backends=("pallas_tpu",)`` and get alignment-pruned automatically.
+    full hls4ml-legal R axis.  The defaults describe one block_batch on the
+    ``"auto"`` backend (compiled on a TPU, interpreted on the CPU); points
+    that resolve to compiled Pallas are alignment-pruned automatically.
     """
 
     reuse_factors: Optional[Tuple[int, ...]] = None
@@ -59,7 +60,7 @@ class SpaceSpec:
     hoist_reuses: Tuple[int, ...] = (1,)
     iis: Tuple[int, ...] = (0,)
     block_batches: Tuple[int, ...] = (8,)
-    backends: Tuple[str, ...] = ("pallas_interpret",)
+    backends: Tuple[str, ...] = ("auto",)
     max_points: int = 4096
 
     def __post_init__(self):
@@ -69,20 +70,21 @@ class SpaceSpec:
 
 
 def _tpu_aligned(schedule: KernelSchedule, gate_dim: int) -> bool:
-    """True when a pallas_tpu schedule passes the Mosaic alignment rules
-    (non-TPU backends are unconstrained)."""
-    if schedule.backend != "pallas_tpu":
-        return True
+    """True when the schedule passes the Mosaic alignment rules wherever it
+    resolves to compiled Pallas (interpreted and XLA points are
+    unconstrained)."""
     import math
 
     from repro.kernels.ops import check_tpu_alignment
     try:
         r = schedule.effective_reuse(gate_dim)
         check_tpu_alignment(schedule, tile_width=gate_dim // r,
+                            full_width=gate_dim,
                             block_batch=schedule.block_batch, kernel="space")
         if schedule.hoist_reuse > 1:
             hr = math.gcd(schedule.hoist_reuse, gate_dim)
             check_tpu_alignment(schedule, tile_width=gate_dim // hr,
+                                full_width=gate_dim,
                                 block_batch=schedule.block_batch,
                                 kernel="space")
     except ValueError:

@@ -24,7 +24,6 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.config import RNNConfig
 from repro.core.rnn.cells import gru_cell, lstm_cell
-from repro.kernels.compat import shard_map
 
 
 def pipelined_rnn(
@@ -109,6 +108,6 @@ def pipelined_rnn(
         return out
 
     in_specs = (P(None, axis, None), P(), P(), P())
-    fn = shard_map(stage_fn, mesh=mesh, in_specs=in_specs,
+    fn = jax.shard_map(stage_fn, mesh=mesh, in_specs=in_specs,
                        out_specs=P(), check_vma=False)
     return fn(xs, W, U, b)

@@ -14,10 +14,10 @@ Every scan kernel dispatches through the reuse-factor scheduling layer
 (schedule.KernelSchedule via ops.py): reuse_factor partitions gate matmuls
 into sequential column tiles, mode selects static (one weights-resident
 block) vs non-static (one block per timestep), and the same schedule object
-feeds core.hls's latency/DSP estimators.  compat.py absorbs JAX API drift
-(TPUCompilerParams/CompilerParams, sharding.AxisType).
+feeds core.hls's latency/DSP estimators.
 
-Kernels target TPU (Mosaic); this container is CPU-only so tests run them
-with interpret=True against the pure-jnp oracles in ref.py.  The XLA model
-paths are used for dry-run lowering (DESIGN.md Sec. 3).
+Kernels target TPU (Mosaic).  ``backend="auto"`` compiles them on a TPU and
+runs them in the Pallas interpreter on the CPU, where the tests check them
+against the pure-jnp oracles in ref.py.  The XLA model paths are used for
+dry-run lowering (DESIGN.md Sec. 3).
 """

@@ -43,8 +43,8 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import tpu_compiler_params
 from repro.kernels.ops import _pad_axis, check_tpu_alignment, resident
 from repro.kernels.schedule import KernelSchedule
 
@@ -85,7 +85,7 @@ def decode_matmul_pallas(x: jax.Array, w: jax.Array, *, reuse: int = 1,
         ],
         out_specs=pl.BlockSpec((block_m, N), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((M, N), x.dtype),
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
     )(x, w)
@@ -106,7 +106,8 @@ def decode_matmul(x: jax.Array, w: jax.Array, *,
     M = x.shape[0]
     bm = min(schedule.block_batch, max(8, M))
     check_tpu_alignment(schedule, tile_width=w.shape[-1] // re,
-                        block_batch=bm, kernel="decode_matmul")
+                        full_width=w.shape[-1], block_batch=bm,
+                        kernel="decode_matmul")
     x_p = _pad_axis(x, 0, bm)
     out = decode_matmul_pallas(x_p, w, reuse=re, block_m=bm,
                                interpret=schedule.interpret)

@@ -6,7 +6,7 @@ sequential time grid.  GRU has 3 gate groups (z|r|hh) and the Hadamard
 product sits inside the candidate tanh (r * (h U_h + b_rec)), so the kernel
 accumulates the input-side (zx) and recurrent-side (zh) pre-activations in
 separate scratches across the R sequential column tiles and combines them at
-the last tile.
+the last tile.  Inputs are time-major with 2-D bias tiles, as in lstm_scan.
 
 Hoisted variant (``gru_scan_hoisted_pallas``): zx = x W + b_in for ALL
 timesteps is computed outside the scan (ops.py's hoist stage) — the GRU is
@@ -24,7 +24,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import tpu_compiler_params
+from repro.kernels.lstm_scan import store_col_tile
 
 
 def _gate_update(zx, zh, h, hidden: int):
@@ -41,21 +41,22 @@ def _gru_kernel(x_ref, w_ref, u_ref, b_ref, out_ref, zx_scr, zh_scr, h_scr,
                 *, hidden: int, seq_len: int, reuse: int):
     t = pl.program_id(1)
     r = pl.program_id(2)
-    gw = (3 * hidden) // reuse
 
     @pl.when(jnp.logical_and(t == 0, r == 0))
     def _init():
         h_scr[...] = jnp.zeros_like(h_scr)
 
-    x_t = x_ref[:, 0, :]
+    x_t = x_ref[...]
     h = h_scr[...]
-    b_in = b_ref[0]                                        # [gw]
-    b_rec = b_ref[1]
+    b_in = b_ref[0:1, :]                                   # [1, gw]
+    b_rec = b_ref[1:2, :]
 
-    zx_scr[:, pl.ds(r * gw, gw)] = (
-        jnp.dot(x_t, w_ref[...], preferred_element_type=jnp.float32) + b_in)
-    zh_scr[:, pl.ds(r * gw, gw)] = (
-        jnp.dot(h, u_ref[...], preferred_element_type=jnp.float32) + b_rec)
+    store_col_tile(zx_scr, r, (
+        jnp.dot(x_t, w_ref[...], preferred_element_type=jnp.float32) + b_in),
+        reuse)
+    store_col_tile(zh_scr, r, (
+        jnp.dot(h, u_ref[...], preferred_element_type=jnp.float32) + b_rec),
+        reuse)
 
     @pl.when(r == reuse - 1)
     def _update():
@@ -75,16 +76,15 @@ def _gru_hoisted_kernel(zx_ref, u_ref, b_ref, out_ref, zx_scr, zh_scr, h_scr,
     the (x_t, W-tile) dot."""
     t = pl.program_id(1)
     r = pl.program_id(2)
-    gw = (3 * hidden) // reuse
 
     @pl.when(jnp.logical_and(t == 0, r == 0))
     def _init():
         h_scr[...] = jnp.zeros_like(h_scr)
 
-    zx_scr[:, pl.ds(r * gw, gw)] = zx_ref[:, 0, :]
-    zh_scr[:, pl.ds(r * gw, gw)] = (
+    store_col_tile(zx_scr, r, zx_ref[...], reuse)
+    store_col_tile(zh_scr, r, (
         jnp.dot(h_scr[...], u_ref[...], preferred_element_type=jnp.float32)
-        + b_ref[...])
+        + b_ref[...]), reuse)
 
     @pl.when(r == reuse - 1)
     def _update():
@@ -99,8 +99,9 @@ def _gru_hoisted_kernel(zx_ref, u_ref, b_ref, out_ref, zx_scr, zh_scr, h_scr,
 def gru_scan_pallas(xs: jax.Array, W: jax.Array, U: jax.Array,
                     b: jax.Array, *, block_batch: int = 128,
                     reuse: int = 1, interpret: bool = True) -> jax.Array:
-    """xs: [B, T, in]; W: [in, 3h]; U: [h, 3h]; b: [2, 3h] -> h [B, h]."""
-    B, T, fin = xs.shape
+    """xs: [T, B, in] (time-major); W: [in, 3h]; U: [h, 3h]; b: [2, 3h]
+    -> h [B, h]."""
+    T, B, fin = xs.shape
     hidden = U.shape[0]
     assert B % block_batch == 0
     assert (3 * hidden) % reuse == 0
@@ -112,7 +113,8 @@ def gru_scan_pallas(xs: jax.Array, W: jax.Array, U: jax.Array,
         kernel,
         grid=(B // block_batch, T, reuse),
         in_specs=[
-            pl.BlockSpec((block_batch, 1, fin), lambda i, t, r: (i, t, 0)),
+            pl.BlockSpec((None, block_batch, fin),
+                         lambda i, t, r: (t, i, 0)),
             pl.BlockSpec((fin, gw), lambda i, t, r: (0, r)),
             pl.BlockSpec((hidden, gw), lambda i, t, r: (0, r)),
             pl.BlockSpec((2, gw), lambda i, t, r: (0, r)),
@@ -124,7 +126,7 @@ def gru_scan_pallas(xs: jax.Array, W: jax.Array, U: jax.Array,
             pltpu.VMEM((block_batch, 3 * hidden), jnp.float32),
             pltpu.VMEM((block_batch, hidden), jnp.float32),
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary")),
         interpret=interpret,
     )(xs, W, U, b)
@@ -143,16 +145,15 @@ def _gru_pipeline_kernel(zx_ref, u_ref, b_ref, out_ref, h_scr, *,
         h_scr[...] = jnp.zeros_like(h_scr)
 
     h = h_scr[...]
-    zx = zx_ref[:, 0, :]                                   # [bt, 3h], b_in in
+    zx = zx_ref[...]                                       # [bt, 3h], b_in in
     u = u_ref[...]
-    b_rec = b_ref[...]
     parts = [
         jnp.dot(h, u[:, r * gw:(r + 1) * gw],
                 preferred_element_type=jnp.float32)
-        + b_rec[r * gw:(r + 1) * gw]
         for r in range(reuse)
     ]
-    zh = parts[0] if reuse == 1 else jnp.concatenate(parts, axis=-1)
+    zh = (parts[0] if reuse == 1
+          else jnp.concatenate(parts, axis=-1)) + b_ref[...]
     h_new = _gate_update(zx, zh, h, hidden)
     h_scr[...] = h_new
 
@@ -165,9 +166,10 @@ def gru_scan_pipeline_pallas(zx: jax.Array, U: jax.Array, b_rec: jax.Array,
                              *, block_batch: int = 128, reuse: int = 1,
                              interpret: bool = True,
                              out_dtype=None) -> jax.Array:
-    """zx: [B, T, 3h] precomputed x W + b_in (f32); U: [h, 3h]; b_rec: [3h]
-    -> final h [B, h].  Grid (B/bt, T): the pipelined NONSTATIC executor."""
-    B, T, gh = zx.shape
+    """zx: [T, B, 3h] time-major precomputed x W + b_in (f32); U: [h, 3h];
+    b_rec: [3h] -> final h [B, h].  Grid (B/bt, T): the pipelined NONSTATIC
+    executor."""
+    T, B, gh = zx.shape
     hidden = U.shape[0]
     assert gh == 3 * hidden
     assert B % block_batch == 0
@@ -179,10 +181,10 @@ def gru_scan_pipeline_pallas(zx: jax.Array, U: jax.Array, b_rec: jax.Array,
         kernel,
         grid=(B // block_batch, T),
         in_specs=[
-            pl.BlockSpec((block_batch, 1, 3 * hidden),
-                         lambda i, t: (i, t, 0)),
+            pl.BlockSpec((None, block_batch, 3 * hidden),
+                         lambda i, t: (t, i, 0)),
             pl.BlockSpec((hidden, 3 * hidden), lambda i, t: (0, 0)),
-            pl.BlockSpec((3 * hidden,), lambda i, t: (0,)),
+            pl.BlockSpec((1, 3 * hidden), lambda i, t: (0, 0)),
         ],
         out_specs=pl.BlockSpec((block_batch, hidden), lambda i, t: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((B, hidden),
@@ -191,23 +193,23 @@ def gru_scan_pipeline_pallas(zx: jax.Array, U: jax.Array, b_rec: jax.Array,
         scratch_shapes=[
             pltpu.VMEM((block_batch, hidden), jnp.float32),
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(zx, U, b_rec)
+    )(zx, U, b_rec.reshape(1, -1))
 
 
 def gru_scan_hoisted_pallas(zx: jax.Array, U: jax.Array, b_rec: jax.Array,
                             *, block_batch: int = 128, reuse: int = 1,
                             interpret: bool = True,
                             out_dtype=None) -> jax.Array:
-    """zx: [B, T, 3h] precomputed x W + b_in (f32); U: [h, 3h];
+    """zx: [T, B, 3h] time-major precomputed x W + b_in (f32); U: [h, 3h];
     b_rec: [3h] recurrent bias -> final h [B, h].
 
     Same (B/bt, T, R) sequential grid as ``gru_scan_pallas``; the live
     weight tile per step shrinks from (fin + h) x gw to h x gw.
     """
-    B, T, gh = zx.shape
+    T, B, gh = zx.shape
     hidden = U.shape[0]
     assert gh == 3 * hidden
     assert B % block_batch == 0
@@ -220,9 +222,10 @@ def gru_scan_hoisted_pallas(zx: jax.Array, U: jax.Array, b_rec: jax.Array,
         kernel,
         grid=(B // block_batch, T, reuse),
         in_specs=[
-            pl.BlockSpec((block_batch, 1, gw), lambda i, t, r: (i, t, r)),
+            pl.BlockSpec((None, block_batch, gw),
+                         lambda i, t, r: (t, i, r)),
             pl.BlockSpec((hidden, gw), lambda i, t, r: (0, r)),
-            pl.BlockSpec((gw,), lambda i, t, r: (r,)),
+            pl.BlockSpec((1, gw), lambda i, t, r: (0, r)),
         ],
         out_specs=pl.BlockSpec((block_batch, hidden), lambda i, t, r: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((B, hidden),
@@ -233,7 +236,7 @@ def gru_scan_hoisted_pallas(zx: jax.Array, U: jax.Array, b_rec: jax.Array,
             pltpu.VMEM((block_batch, 3 * hidden), jnp.float32),
             pltpu.VMEM((block_batch, hidden), jnp.float32),
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary")),
         interpret=interpret,
-    )(zx, U, b_rec)
+    )(zx, U, b_rec.reshape(1, -1))

@@ -29,14 +29,15 @@ in-loop paths are bit-identical: the pre-activation keeps the association
 The same schedule object drives ``core.hls.resources.estimate_schedule`` so
 software latency/resource numbers describe exactly what executes here.
 
-TPU lane alignment (ROADMAP open item): on ``backend="pallas_tpu"`` the
-per-reuse column tile is a lane-dimension block — Mosaic requires its width
-to be a multiple of 128 (and the batch tile a multiple of 8 sublanes).  The
-dispatch validates this at schedule-application time and raises a clear
-ValueError instead of miscompiling on hardware.
+TPU lane alignment: when a schedule runs compiled Mosaic, the per-reuse
+column tile is a lane-dimension block — Mosaic requires it to span the whole
+gate width or be a multiple of 128 lanes (and the batch tile a multiple of 8
+sublanes).  The dispatch validates this at schedule-application time and
+raises a clear ValueError instead of failing inside the compiler.
 
-CPU containers run interpret=True; on a real TPU either set
-REPRO_PALLAS_INTERPRET=0 or use backend="pallas_tpu".
+``backend="auto"`` compiles on a TPU and interprets on the CPU
+(``schedule.resolve_interpret``).  The scan kernels take time-major inputs;
+the wrappers here transpose ``[B, T, ...]`` once before the kernel call.
 """
 
 from __future__ import annotations
@@ -62,8 +63,7 @@ from repro.kernels.lstm_scan import (lstm_scan_hoisted_pallas,
                                      lstm_scan_pipeline_pallas)
 from repro.kernels.reuse_matmul import col_matmul_pallas, reuse_matmul_pallas
 from repro.kernels.rglru_scan import rglru_scan_pallas
-from repro.kernels.schedule import KernelSchedule
-from repro.kernels.schedule import _env_interpret as _interpret
+from repro.kernels.schedule import KernelSchedule, resolve_interpret
 
 #: Mosaic tiling floors for f32 blocks — last dim lanes, second-to-last
 #: sublanes; a column tile off these boundaries miscompiles on hardware
@@ -72,27 +72,30 @@ TPU_SUBLANES = 8
 
 
 def check_tpu_alignment(schedule: KernelSchedule, *, tile_width: int,
-                        block_batch: int, kernel: str) -> None:
-    """Validate Mosaic lane alignment for a real-hardware schedule.
+                        full_width: int, block_batch: int,
+                        kernel: str) -> None:
+    """Validate Mosaic tiling for a schedule that runs compiled Pallas.
 
-    ROADMAP open item: on ``backend="pallas_tpu"`` the per-reuse column tile
-    of width ``tile_width`` is a lane-dim block and the batch tile spans
-    sublanes.  Interpret/XLA backends have no such constraint, so the check
-    only fires for the hardware backend — raising at schedule-application
-    time with an actionable message instead of miscompiling.
+    The per-reuse column tile of width ``tile_width`` (out of a gate
+    dimension of ``full_width``) is a lane-dim block: Mosaic takes it when
+    it spans the whole dimension or is a multiple of 128 lanes.  The batch
+    tile spans sublanes.  Interpreted and XLA schedules have no such
+    constraint, so the check applies only where the schedule resolves to
+    compiled Mosaic — raising at schedule-application time with an
+    actionable message instead of failing inside the compiler.
     """
-    if schedule.backend != "pallas_tpu":
+    if not schedule.use_pallas or schedule.interpret:
         return
-    if tile_width % TPU_LANES != 0:
+    if tile_width != full_width and tile_width % TPU_LANES != 0:
         raise ValueError(
-            f"{kernel}: pallas_tpu column tile width {tile_width} is not a "
-            f"multiple of {TPU_LANES} lanes (schedule {schedule.key()}). "
-            f"Pick a reuse factor so the per-reuse tile width is "
-            f"128-aligned, or pad the gate dimension, or use "
-            f"backend='pallas_interpret' off-hardware.")
+            f"{kernel}: compiled column tile width {tile_width} of "
+            f"{full_width} is not a multiple of {TPU_LANES} lanes (schedule "
+            f"{schedule.key()}). Pick a reuse factor so the per-reuse tile "
+            f"is the whole gate width or 128-aligned, or pad the gate "
+            f"dimension.")
     if block_batch % TPU_SUBLANES != 0:
         raise ValueError(
-            f"{kernel}: pallas_tpu batch tile {block_batch} is not a "
+            f"{kernel}: compiled batch tile {block_batch} is not a "
             f"multiple of {TPU_SUBLANES} sublanes (schedule "
             f"{schedule.key()}). Use a block_batch that is 8-aligned.")
 
@@ -105,6 +108,13 @@ def _pad_axis(x: jax.Array, axis: int, multiple: int) -> jax.Array:
     widths = [(0, 0)] * x.ndim
     widths[axis] = (0, pad)
     return jnp.pad(x, widths)
+
+
+def _time_major(xs: jax.Array, bt: int) -> jax.Array:
+    """[B, T, ...] -> [T, B_pad, ...] with the batch padded to the batch
+    tile: the scan kernels' layout, where each timestep is one
+    (batch-tile, features) block."""
+    return jnp.swapaxes(_pad_axis(xs, 0, bt), 0, 1)
 
 
 def _resolve(schedule: Optional[KernelSchedule],
@@ -242,23 +252,25 @@ def _hoist_stage(xs: jax.Array, W: jax.Array,
     """The hoisted input projection: ONE batched [B*T, fin] @ [fin, G*h]
     matmul outside the sequential scan (f32 accumulate, no bias) — the
     embarrassingly parallel half of the gate pre-activation, previously
-    recomputed inside every sequential grid cell.
+    recomputed inside every sequential grid cell.  Layout-agnostic over the
+    leading axes: ``[..., fin] -> [..., G*h]`` (the scan wrappers pass
+    time-major inputs, so zx comes out in the kernels' layout).
 
     Fully parallel (one full-MXU pass) unless the schedule asks for R-tiling
     via ``hoist_reuse``, in which case it runs as sequential column tiles
     through the same ``col_matmul`` kernel the non-static blocks use.
     """
-    B, T, fin = xs.shape
-    flat = xs.reshape(B * T, fin)
+    flat = xs.reshape(-1, xs.shape[-1])
     hr = math.gcd(schedule.hoist_reuse, W.shape[-1])
     if hr > 1:
         check_tpu_alignment(schedule, tile_width=W.shape[-1] // hr,
+                            full_width=W.shape[-1],
                             block_batch=min(128, max(8, flat.shape[0])),
                             kernel="hoist_stage")
         zx = _gate_mm(flat, W, hr, schedule.interpret)
     else:
         zx = jnp.dot(flat, W, preferred_element_type=jnp.float32)
-    return zx.reshape(B, T, W.shape[-1])
+    return zx.reshape(xs.shape[:-1] + (W.shape[-1],))
 
 
 def _cell_pipeline(cell: str, xs, W, U, b,
@@ -273,10 +285,9 @@ def _cell_pipeline(cell: str, xs, W, U, b,
     g = 4 if cell == "lstm" else 3
     re = schedule.effective_reuse(g * H)
     bt = min(schedule.block_batch, max(8, B))
-    check_tpu_alignment(schedule, tile_width=g * H // re, block_batch=bt,
-                        kernel=f"{cell}_scan")
-    xs_p = _pad_axis(xs, 0, bt)
-    zx = _hoist_stage(xs_p, W, schedule)
+    check_tpu_alignment(schedule, tile_width=g * H // re, full_width=g * H,
+                        block_batch=bt, kernel=f"{cell}_scan")
+    zx = _hoist_stage(_time_major(xs, bt), W, schedule)
     if cell == "lstm":
         out = lstm_scan_pipeline_pallas(zx, U, b, block_batch=bt, reuse=re,
                                         interpret=schedule.interpret,
@@ -306,7 +317,7 @@ def _cell_unrolled(cell: str, xs, W, U, b,
     g = 4 if cell == "lstm" else 3
     re = schedule.effective_reuse(g * H)
     itp = schedule.interpret
-    check_tpu_alignment(schedule, tile_width=g * H // re,
+    check_tpu_alignment(schedule, tile_width=g * H // re, full_width=g * H,
                         block_batch=min(128, max(8, B)),
                         kernel=f"{cell}_scan")
 
@@ -318,6 +329,7 @@ def _cell_unrolled(cell: str, xs, W, U, b,
         flat = xs.reshape(B * T, -1)
         hr = math.gcd(schedule.hoist_reuse, g * H)
         check_tpu_alignment(schedule, tile_width=g * H // hr,
+                            full_width=g * H,
                             block_batch=min(128, max(8, flat.shape[0])),
                             kernel="hoist_stage")
         # same col-serialized kernel as the in-loop blocks -> bit-identical
@@ -412,15 +424,16 @@ def _lstm_scan_jit(xs, W, U, b, *, schedule: KernelSchedule):
     bt = min(schedule.block_batch, max(8, B))
     reuse = schedule.effective_reuse(4 * U.shape[0])
     check_tpu_alignment(schedule, tile_width=4 * U.shape[0] // reuse,
-                        block_batch=bt, kernel="lstm_scan")
-    xs_p = _pad_axis(xs, 0, bt)
+                        full_width=4 * U.shape[0], block_batch=bt,
+                        kernel="lstm_scan")
+    xs_t = _time_major(xs, bt)
     if schedule.hoist_input:
-        zx = _hoist_stage(xs_p, W, schedule)
+        zx = _hoist_stage(xs_t, W, schedule)
         out = lstm_scan_hoisted_pallas(zx, U, b, block_batch=bt, reuse=reuse,
                                        interpret=schedule.interpret,
                                        out_dtype=xs.dtype)
     else:
-        out = lstm_scan_pallas(xs_p, W, U, b, block_batch=bt, reuse=reuse,
+        out = lstm_scan_pallas(xs_t, W, U, b, block_batch=bt, reuse=reuse,
                                interpret=schedule.interpret)
     return out[:B]
 
@@ -449,19 +462,20 @@ def _gru_scan_jit(xs, W, U, b, *, schedule: KernelSchedule):
     bt = min(schedule.block_batch, max(8, B))
     reuse = schedule.effective_reuse(3 * U.shape[0])
     check_tpu_alignment(schedule, tile_width=3 * U.shape[0] // reuse,
-                        block_batch=bt, kernel="gru_scan")
-    xs_p = _pad_axis(xs, 0, bt)
+                        full_width=3 * U.shape[0], block_batch=bt,
+                        kernel="gru_scan")
+    xs_t = _time_major(xs, bt)
     if schedule.hoist_input:
         # GRU keeps input- and recurrent-side pre-activations separate, so
         # the input bias folds into the hoisted zx (same add order as the
         # in-loop kernel's dot + b_in)
-        zx = _hoist_stage(xs_p, W, schedule) + b[0]
+        zx = _hoist_stage(xs_t, W, schedule) + b[0]
         out = gru_scan_hoisted_pallas(zx, U, b[1], block_batch=bt,
                                       reuse=reuse,
                                       interpret=schedule.interpret,
                                       out_dtype=xs.dtype)
     else:
-        out = gru_scan_pallas(xs_p, W, U, b, block_batch=bt, reuse=reuse,
+        out = gru_scan_pallas(xs_t, W, U, b, block_batch=bt, reuse=reuse,
                               interpret=schedule.interpret)
     return out[:B]
 
@@ -475,7 +489,7 @@ def hadamard(a, b):
     bn = min(1024, rows)
     a2 = _pad_axis(a2, 0, bn)
     b2 = _pad_axis(b2, 0, bn)
-    out = hadamard_pallas(a2, b2, block=bn, interpret=_interpret())
+    out = hadamard_pallas(a2, b2, block=bn, interpret=resolve_interpret())
     return out[:rows].reshape(shape)
 
 
@@ -486,7 +500,8 @@ def fixed_point(x, fp: FixedPointConfig):
         x2 = x.reshape(-1, shape[-1])
         bn = min(1024, x2.shape[0])
         x2 = _pad_axis(x2, 0, bn)
-        out = fixed_point_pallas(x2, fp, block=bn, interpret=_interpret())
+        out = fixed_point_pallas(x2, fp, block=bn,
+                                 interpret=resolve_interpret())
         return out[: (x.size // shape[-1])].reshape(shape)
     return run(x)
 
@@ -552,8 +567,8 @@ def _rglru_scan_jit(a, bx, *, schedule: KernelSchedule,
     reuse = schedule.reuse_factor
     bb = min(schedule.block_batch, max(1, B))
     bw = min(block_width, -(-W // reuse))  # ceil: R sequential width tiles
-    check_tpu_alignment(schedule, tile_width=bw, block_batch=bb,
-                        kernel="rglru_scan")
+    check_tpu_alignment(schedule, tile_width=bw, full_width=W,
+                        block_batch=bb, kernel="rglru_scan")
     a_p = _pad_axis(_pad_axis(a, 0, bb), 2, bw)
     b_p = _pad_axis(_pad_axis(bx, 0, bb), 2, bw)
     out = rglru_scan_pallas(a_p, b_p, block_batch=bb, block_width=bw,
@@ -598,10 +613,11 @@ def _reuse_matmul_jit(x, w, *, reuse: int = 1, block_m: int = 128,
         reuse = schedule.effective_reuse(x.shape[1])
         interpret = schedule.interpret
         check_tpu_alignment(schedule, tile_width=x.shape[1] // reuse,
+                            full_width=x.shape[1],
                             block_batch=min(block_m, max(8, x.shape[0])),
                             kernel="reuse_matmul")
     else:
-        interpret = _interpret()
+        interpret = resolve_interpret()
     M, K = x.shape
     bm = min(block_m, max(8, M))
     x_p = _pad_axis(x, 0, bm)

@@ -41,12 +41,12 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.config import FixedPointConfig
 from repro.core.quant.fixed_point import (from_ints, grid_constants,
                                           is_native_int, native_bits,
                                           quantize, to_ints)
-from repro.kernels.compat import tpu_compiler_params
 from repro.kernels.schedule import KernelSchedule, schedule_key
 
 
@@ -99,13 +99,13 @@ def packed_nbytes(packed) -> int:
 
 
 def _quant_mm_kernel(x_ref, w_ref, o_ref, *, reuse: int, ns: int):
-    """One batch-tile cell: int8 operands, INT32 accumulation, the R output
-    column tiles serialized in-block (the decode kernels' reuse structure —
-    column tiles never split the K reduction, so every output element is
-    the full-K integer dot product)."""
-    x = x_ref[...].astype(jnp.int32)
+    """One batch-tile cell: int8 operands fed to the MXU as int8, INT32
+    accumulation, the R output column tiles serialized in-block (the decode
+    kernels' reuse structure — column tiles never split the K reduction, so
+    every output element is the full-K integer dot product)."""
+    x = x_ref[...]
     for r in range(reuse):
-        w = w_ref[:, r * ns:(r + 1) * ns].astype(jnp.int32)
+        w = w_ref[:, r * ns:(r + 1) * ns]
         o_ref[:, r * ns:(r + 1) * ns] = jax.lax.dot_general(
             x, w, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.int32)
@@ -130,7 +130,7 @@ def quant_matmul_pallas(x: jax.Array, w: jax.Array, *, reuse: int = 1,
         ],
         out_specs=pl.BlockSpec((block_m, N), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((M, N), jnp.int32),
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
     )(x, w)
@@ -170,7 +170,8 @@ def _int_matmul(ai: jax.Array, wq: jax.Array,
     re = schedule.effective_reuse(wq.shape[-1])
     bm = min(schedule.block_batch, max(8, M))
     check_tpu_alignment(schedule, tile_width=wq.shape[-1] // re,
-                        block_batch=bm, kernel="quant_matmul")
+                        full_width=wq.shape[-1], block_batch=bm,
+                        kernel="quant_matmul")
     a_p = _pad_axis(ai, 0, bm)
     out = quant_matmul_pallas(a_p, wq, reuse=re, block_m=bm,
                               interpret=schedule.interpret)

@@ -21,8 +21,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import tpu_compiler_params
-
 
 def _reuse_mm_kernel(x_ref, w_ref, o_ref, acc_scr, *, reuse: int):
     r = pl.program_id(1)
@@ -63,7 +61,7 @@ def reuse_matmul_pallas(x: jax.Array, w: jax.Array, *, reuse: int = 1,
         out_specs=pl.BlockSpec((block_m, N), lambda i, r: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((M, N), x.dtype),
         scratch_shapes=[pltpu.VMEM((block_m, N), jnp.float32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(x, w)
@@ -100,7 +98,7 @@ def col_matmul_pallas(x: jax.Array, w: jax.Array, *, reuse: int = 1,
         ],
         out_specs=pl.BlockSpec((block_m, ns), lambda i, r: (i, r)),
         out_shape=jax.ShapeDtypeStruct((M, N), x.dtype),
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(x, w)

@@ -27,8 +27,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import tpu_compiler_params
-
 
 def _rglru_kernel(a_ref, bx_ref, out_ref, h_scr, *, seq_len: int):
     t = pl.program_id(2)
@@ -73,7 +71,7 @@ def rglru_scan_pallas(a: jax.Array, bx: jax.Array, *,
                                lambda i, j, t: (i, t, j)),
         out_shape=jax.ShapeDtypeStruct((B, T, Wd), a.dtype),
         scratch_shapes=[pltpu.VMEM((block_batch, block_width), jnp.float32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", width_sem, "arbitrary")),
         interpret=interpret,
     )(a, bx)

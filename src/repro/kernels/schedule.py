@@ -25,9 +25,10 @@ Dependency note: this module imports nothing from ``repro`` so that
 from __future__ import annotations
 
 import math
-import os
 from dataclasses import dataclass, replace
 from typing import Iterable, Tuple
+
+import jax
 
 MODES = ("static", "nonstatic", "pipeline")
 BACKENDS = ("auto", "xla", "pallas_interpret", "pallas_tpu")
@@ -36,8 +37,28 @@ BACKENDS = ("auto", "xla", "pallas_interpret", "pallas_tpu")
 DEFAULT_SCHEDULE_KEY = "default"
 
 
-def _env_interpret() -> bool:
-    return os.environ.get("REPRO_PALLAS_INTERPRET", "1") != "0"
+def resolve_interpret(backend: str = "auto") -> bool:
+    """Whether a Pallas kernel on ``backend`` runs in the interpreter.
+
+    The explicit backends decide for themselves; ``"auto"`` follows the
+    platform JAX traces for (read at trace time): compiled Mosaic on
+    ``tpu``, the interpreter on ``cpu``.  Any other platform raises — a
+    kernel never quietly drops to the interpreter on an accelerator.
+    """
+    if backend == "pallas_interpret":
+        return True
+    if backend == "pallas_tpu":
+        return False
+    if backend != "auto":
+        raise ValueError(f"backend {backend!r} runs no Pallas kernel")
+    platform = jax.default_backend()
+    if platform == "tpu":
+        return False
+    if platform == "cpu":
+        return True
+    raise RuntimeError(
+        f"backend='auto' has no Pallas mode for platform {platform!r}: "
+        f"run on tpu (compiled) or cpu (interpreted), or name the backend")
 
 
 @dataclass(frozen=True)
@@ -59,8 +80,8 @@ class KernelSchedule:
                   xW GEMM as a separate fully-pipelined front stage).
     block_batch   batch tile per kernel invocation (TPU sublane analogue of
                   the paper's "independent inferences in flight").
-    backend       "auto" (Pallas; interpret controlled by
-                  REPRO_PALLAS_INTERPRET), "pallas_interpret",
+    backend       "auto" (Pallas, compiled on a TPU and interpreted on the
+                  CPU: ``resolve_interpret``), "pallas_interpret",
                   "pallas_tpu", or "xla" (the lax.scan golden reference).
     hoist_input   compute the input projection xW for ALL timesteps as ONE
                   batched [B*T, fin] @ [fin, G*h] matmul outside the
@@ -125,11 +146,7 @@ class KernelSchedule:
 
     @property
     def interpret(self) -> bool:
-        if self.backend == "pallas_interpret":
-            return True
-        if self.backend == "pallas_tpu":
-            return False
-        return _env_interpret()
+        return resolve_interpret(self.backend)
 
     # -- reuse partitioning -------------------------------------------------
 

@@ -9,17 +9,11 @@ Functions (not module constants) so importing never touches jax device state.
 from __future__ import annotations
 
 import jax
-from jax.sharding import Mesh
-
-from repro.kernels.compat import HAS_AXIS_TYPE, AxisType
+from jax.sharding import AxisType, Mesh
 
 
 def _mk(shape, axes) -> Mesh:
-    if HAS_AXIS_TYPE:
-        return jax.make_mesh(shape, axes,
-                             axis_types=(AxisType.Auto,) * len(axes))
-    # older jax: make_mesh has no axis_types kwarg and every axis is "auto"
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:

@@ -22,6 +22,7 @@ from repro.data import (flavor_tagging_dataset, quickdraw_dataset,
 from repro.models.model import build_model
 from repro.registry import get_config
 from repro.serving import LMServingEngine, RNNServingEngine
+from repro.serving.compile_cache import enable_jax_compilation_cache
 from repro.testing import tiny_config
 
 
@@ -101,6 +102,7 @@ def main():
     ap.add_argument("--fixed-point", action="store_true")
     ap.add_argument("--reuse", type=int, default=1)
     args = ap.parse_args()
+    enable_jax_compilation_cache()
     cfg = get_config(args.arch)
     if cfg.family == "rnn":
         serve_rnn(args.arch, args.mode, args.requests, args.fixed_point,

@@ -24,7 +24,6 @@ import jax.numpy as jnp
 from repro.config import ModelConfig
 from repro.models.init import ParamSpec
 from repro.sharding.api import constrain, current_context
-from repro.kernels.compat import shard_map
 
 
 def padded_n_experts(cfg: ModelConfig) -> int:
@@ -86,7 +85,7 @@ def _shard_map_combine(ctx, ye, sel_idx, t, d):
         return jax.lax.psum(out_l, "model")
 
     other = tuple(a for a in ctx.mesh.axis_names if a != "model")
-    fn = shard_map(
+    fn = jax.shard_map(
         combine, mesh=ctx.mesh,
         in_specs=(P("model", None, None), P("model", None)),
         out_specs=P(), check_vma=False)

@@ -10,8 +10,10 @@ frameworks do (export/compile ahead of time, load artifacts at serve time):
   * :class:`CompileCache` serializes compiled XLA executables
     (``jax.jit(...).lower(...).compile()`` +
     ``jax.experimental.serialize_executable``) to a cache directory, one
-    file per content hash of ``{jax/jaxlib version, platform, cfg,
-    schedule_key, fp, argument shapes}``.  Any load / deserialize failure
+    file per content hash of ``{jax/jaxlib version, platform, device,
+    cfg, schedule_key, fp, argument shapes}``.  An executable is bound to
+    the device it was compiled for, so each device has its own entries and
+    loads them back onto that device.  Any load / deserialize failure
     degrades gracefully to a fresh compile (warn, never crash) — a
     corrupted or stale entry costs one cold compile, not an outage.
   * :class:`CachedExecutor` wraps one jit'd function and dispatches each
@@ -26,6 +28,9 @@ frameworks do (export/compile ahead of time, load artifacts at serve time):
 
 Per-logical-key cold/warm counters feed the engines' ``serve_report``
 (the ``compile`` column: hit rate + first-request compile seconds).
+
+:func:`enable_jax_compilation_cache` is the one place entry points turn on
+JAX's own persistent compilation cache.
 """
 
 from __future__ import annotations
@@ -37,7 +42,8 @@ import pickle
 import time
 import uuid
 import warnings
-from dataclasses import dataclass, field
+from dataclasses import dataclass
+from functools import lru_cache
 from pathlib import Path
 from typing import Any, Callable, Dict, Optional, Tuple
 
@@ -46,22 +52,53 @@ _FORMAT_VERSION = 1
 
 _SUFFIX = ".jaxcache"
 
+_PACKAGE = Path(__file__).resolve().parents[1]
 
-def _env_meta() -> Dict[str, str]:
+#: JAX's persistent compilation cache when JAX_COMPILATION_CACHE_DIR is
+#: unset: one fixed path per checkout, ignored by git
+REPO_JAX_CACHE = _PACKAGE.parents[1] / ".jax_cache"
+
+
+def enable_jax_compilation_cache() -> str:
+    """Turn on JAX's persistent compilation cache for an entry point and
+    return its directory.  Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX
+    already uses it and nothing else is set; otherwise the cache lives at
+    :data:`REPO_JAX_CACHE`, a fixed path so that later runs find it."""
+    import jax
+
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        jax.config.update("jax_compilation_cache_dir", str(REPO_JAX_CACHE))
+    return jax.config.jax_compilation_cache_dir
+
+
+@lru_cache(maxsize=1)
+def _source_digest() -> str:
+    """Hash of this package's Python sources: an executable built from
+    other kernel code must not load, even from a directory that outlives a
+    checkout."""
+    h = hashlib.sha256()
+    for f in sorted(_PACKAGE.rglob("*.py")):
+        h.update(f.relative_to(_PACKAGE).as_posix().encode())
+        h.update(f.read_bytes())
+    return h.hexdigest()[:16]
+
+
+def _env_meta(device) -> Dict[str, str]:
     """The toolchain axes that invalidate a serialized executable: an
-    artifact compiled by one jaxlib for one platform must never be fed to
-    another."""
+    artifact compiled by one jaxlib for one platform, or bound to one
+    device, must never be fed to another."""
     import jax
     import jaxlib
 
-    devs = jax.devices()
     return {
         "format": str(_FORMAT_VERSION),
+        "source": _source_digest(),
         "jax": jax.__version__,
         "jaxlib": jaxlib.__version__,
         "platform": jax.default_backend(),
-        "n_devices": str(len(devs)),
-        "device_kind": devs[0].device_kind if devs else "none",
+        "n_devices": str(len(jax.devices())),
+        "device_kind": device.device_kind,
+        "device_id": str(device.id),
     }
 
 
@@ -104,14 +141,20 @@ class CompileCache:
     ``cache_dir=None`` disables persistence but keeps the accounting: every
     signature then costs exactly one in-process cold compile (the pre-PR
     behavior), and ``serve_report`` still shows honest cold counts.
+    ``device`` is the device the cached executables run on (default: the
+    first device).
     """
 
-    def __init__(self, cache_dir: Optional[os.PathLike | str] = None):
+    def __init__(self, cache_dir: Optional[os.PathLike | str] = None,
+                 device=None):
+        import jax
+
         self.dir = Path(cache_dir) if cache_dir is not None else None
         self.enabled = self.dir is not None
         if self.enabled:
             self.dir.mkdir(parents=True, exist_ok=True)
-        self._env = _env_meta()
+        self.device = device if device is not None else jax.devices()[0]
+        self._env = _env_meta(self.device)
         self._stats: Dict[str, KeyCompileStats] = {}
         # negative cache: entry paths that already failed to deserialize.
         # Without it a known-corrupt entry was re-read, re-unpickled and
@@ -183,7 +226,8 @@ class CompileCache:
                     f"entry metadata mismatch (hash collision or stale "
                     f"format): {path.name}")
             return serialize_executable.deserialize_and_load(
-                doc["payload"], doc["in_tree"], doc["out_tree"])
+                doc["payload"], doc["in_tree"], doc["out_tree"],
+                execution_devices=[self.device])
         except Exception as e:  # corrupted/stale entry: warn ONCE, fall back
             self.stats(key).errors += 1
             self._quarantine.add(str(path))
@@ -302,3 +346,7 @@ class CachedExecutor:
 
     def compiled_signatures(self) -> int:
         return len(self._compiled)
+
+    def executables(self) -> list:
+        """The compiled executables acquired so far, one per signature."""
+        return list(self._compiled.values())

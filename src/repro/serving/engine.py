@@ -80,6 +80,9 @@ class RNNServingEngine:
                                           # warm dir serves the first request
                                           # of a FRESH engine with zero jit
                                           # compiles (N replicas may share it)
+    device: Optional[jax.Device] = None   # where params, inputs and the
+                                          # executables live (None: the
+                                          # default device)
     _infer_cache: Dict[str, Callable] = field(default_factory=dict, repr=False)
     _key_specs: Dict[str, Tuple[KernelSchedule, Optional[FixedPointConfig]]] \
         = field(default_factory=dict, repr=False)
@@ -97,7 +100,16 @@ class RNNServingEngine:
         if self.ragged not in RAGGED_POLICIES:
             raise ValueError(f"ragged {self.ragged!r} not in {RAGGED_POLICIES}")
         self.batcher = MicroBatcher(max_batch=self.max_batch)
-        self.compile_cache = CompileCache(self.cache_dir)
+        self.compile_cache = CompileCache(self.cache_dir,
+                                          device=self.device)
+        if self.device is not None:
+            self.params = jax.device_put(self.params, self.device)
+
+    def _put(self, x) -> jax.Array:
+        """An input array on this engine's device."""
+        if not isinstance(x, jax.Array):
+            x = np.asarray(x)
+        return jax.device_put(x, self.device)
 
     # -- schedule resolution -------------------------------------------------
 
@@ -131,7 +143,7 @@ class RNNServingEngine:
         """The slice of schedule space this engine can execute: its backend
         family, a kernel-friendly block_batch, the full legal R/mode/hoist
         axes.  Callers needing other axes pass an explicit spec."""
-        backend = "xla" if self.impl == "xla" else "pallas_interpret"
+        backend = "xla" if self.impl == "xla" else "auto"
         return SpaceSpec(backends=(backend,),
                          block_batches=(min(8, self.max_batch),))
 
@@ -241,9 +253,9 @@ class RNNServingEngine:
                      lengths: Optional[np.ndarray] = None) -> np.ndarray:
         fn = self._infer_cache[self._resolve_default_key(key)]
         if lengths is None:
-            return np.asarray(fn(self.params, jnp.asarray(x)))
-        return np.asarray(fn(self.params, jnp.asarray(x),
-                             jnp.asarray(lengths, jnp.int32)))
+            return np.asarray(fn(self.params, self._put(x)))
+        return np.asarray(fn(self.params, self._put(x),
+                             self._put(np.asarray(lengths, np.int32))))
 
     def predict(self, x: np.ndarray,
                 schedule: Optional[KernelSchedule] = None,
@@ -326,8 +338,10 @@ class RNNServingEngine:
             key = self._ensure_key(*self.resolve(sched, fp))
             mb, _ = self.batcher.policy(key)
             rows = mb if self.pad_batches else 1
-            x = jax.ShapeDtypeStruct((rows, r.seq_len, r.input_size),
-                                     jnp.float32)
+            x = jax.ShapeDtypeStruct(
+                (rows, r.seq_len, r.input_size), jnp.float32,
+                sharding=jax.sharding.SingleDeviceSharding(
+                    self.compile_cache.device))
             out[key] = self._infer_cache[key].warm(self.params, x)
         return out
 
@@ -376,7 +390,9 @@ class RNNServingEngine:
             fn = self._one_cache[key] = self._make_one_infer(key, sched, fpr)
         traces_before = self._one_traces.get(key, 0)
         t0 = time.perf_counter()
-        out = np.asarray(fn(self.params, jnp.asarray(x)[None]))[0]
+        x1 = (x if isinstance(x, (np.ndarray, jax.Array))
+              else np.asarray(x))[None]
+        out = np.asarray(fn(self.params, self._put(x1)))[0]
         if self._one_traces.get(key, 0) == traces_before:   # steady state
             self._one_stats.setdefault(key, KeyStats()).record_one(
                 time.perf_counter() - t0)
@@ -384,6 +400,14 @@ class RNNServingEngine:
 
     def one_trace_count(self, key: str) -> int:
         return self._one_traces.get(key, 0)
+
+    def executables(self) -> Dict[str, List]:
+        """Every compiled executable this engine has served: the batched
+        path under its schedule key, the batch-1 path under ``<key>-one``."""
+        out = {k: fn.executables() for k, fn in self._infer_cache.items()}
+        out.update({f"{k}-one": fn.executables()
+                    for k, fn in self._one_cache.items()})
+        return out
 
     # -- lifecycle -----------------------------------------------------------
 

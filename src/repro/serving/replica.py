@@ -22,6 +22,7 @@ from __future__ import annotations
 
 from typing import Callable, Dict, Iterator, List, Optional, Tuple
 
+import jax
 import numpy as np
 
 from repro.config import FixedPointConfig, ModelConfig
@@ -113,10 +114,12 @@ class ReplicaPool:
 
     ``build`` is the canonical constructor: one (cfg, params) pair, N
     fresh :class:`RNNServingEngine` instances (each with its own batcher
-    and jit state — replicas share NO mutable serving state), all pointed
-    at the same ``cache_dir`` so the first replica to compile a schedule
-    key stores the executable every other replica (and every failover)
-    deserializes — zero-warmup failover.
+    and jit state — replicas share NO mutable serving state), replica i on
+    device i (round-robin when there are fewer devices than replicas), all
+    pointed at the same ``cache_dir``.  Executables are bound to their
+    device, so the directory holds one entry per (schedule key, device):
+    a failover onto a device that has served the key before starts
+    zero-warmup.
     """
 
     def __init__(self, replicas: List[EngineReplica]):
@@ -136,13 +139,17 @@ class ReplicaPool:
         """N replicas of one model.  ``make_engine(i)`` overrides engine
         construction (tests inject pre-warmed or oddly configured
         engines); the default builds ``RNNServingEngine(cfg, params,
-        cache_dir=cache_dir, **engine_kw)`` per replica."""
+        cache_dir=cache_dir, device=jax.devices()[i % n_devices],
+        **engine_kw)`` per replica — params and executables on its own
+        device."""
         if n < 1:
             raise ValueError(f"replica count must be >= 1: {n}")
+        devices = jax.devices()
         reps = []
         for i in range(n):
             eng = (make_engine(i) if make_engine is not None
                    else RNNServingEngine(cfg, params, cache_dir=cache_dir,
+                                         device=devices[i % len(devices)],
                                          **engine_kw))
             reps.append(EngineReplica(f"r{i}", eng))
         return cls(reps)

@@ -87,12 +87,18 @@ def test_space_full_reuse_axis_is_divisors():
 
 
 def test_space_prunes_misaligned_tpu_points():
-    """pallas_tpu points whose column tile is off the 128-lane boundary are
-    pruned (they would raise at dispatch), never clamped."""
+    """pallas_tpu points whose partial column tile is off the 128-lane
+    boundary are pruned (they would raise at dispatch), never clamped; the
+    whole-width tile (R=1) is legal at any width."""
     spec = SpaceSpec(reuse_factors=None, modes=("static",), hoist=(False,),
                      block_batches=(8,), backends=("pallas_tpu",))
     gd = gate_count(CFG.rnn.cell) * CFG.rnn.hidden   # 80: no 128-wide tile
-    assert enumerate_space(CFG, spec) == ()
+    assert gd == 80
+    assert [s.reuse_factor for s in enumerate_space(CFG, spec)] == [1]
+    partial = SpaceSpec(reuse_factors=(2,), modes=("static",),
+                        hoist=(False,), block_batches=(8,),
+                        backends=("pallas_tpu",))
+    assert enumerate_space(CFG, partial) == ()
     big = get_config("quickdraw-lstm")               # h=128 -> gd=512
     aligned = enumerate_space(big, spec)
     assert aligned
@@ -290,9 +296,9 @@ def test_select_measured_refinement_never_degrades_resources_objective():
 
 
 def test_select_empty_space_raises_clear_error():
-    """An all-pruned space (e.g. pallas_tpu alignment on gate_dim 80) must
-    raise an explanatory ValueError, not min()-on-empty."""
-    spec = SpaceSpec(modes=("static",), hoist=(False,),
+    """An all-pruned space (e.g. pallas_tpu alignment of R=2 tiles on
+    gate_dim 80) must raise an explanatory ValueError, not min()-on-empty."""
+    spec = SpaceSpec(reuse_factors=(2,), modes=("static",), hoist=(False,),
                      backends=("pallas_tpu",))
     assert enumerate_space(CFG, spec) == ()
     with pytest.raises(ValueError, match="space is empty"):

@@ -10,6 +10,7 @@ counterpart at the same (mode, R, dtype): hoisting only moves the xW half of
 (xW + hU) + b outside the scan without changing the association order.
 """
 
+import jax
 import numpy as np
 import pytest
 
@@ -268,17 +269,69 @@ def test_tpu_alignment_rejects_misaligned_tiles():
     from repro.kernels.ops import check_tpu_alignment
 
     tpu = KernelSchedule(backend="pallas_tpu", reuse_factor=2)
-    # 4h = 80, R = 2 -> gw = 40: not a 128 multiple
+    # 4h = 80, R = 2 -> gw = 40: a partial tile off the 128-lane boundary
     with pytest.raises(ValueError, match="multiple of 128"):
-        check_tpu_alignment(tpu, tile_width=40, block_batch=8,
-                            kernel="lstm_scan")
+        check_tpu_alignment(tpu, tile_width=40, full_width=80,
+                            block_batch=8, kernel="lstm_scan")
     with pytest.raises(ValueError, match="sublanes"):
-        check_tpu_alignment(tpu, tile_width=256, block_batch=5,
-                            kernel="lstm_scan")
-    # aligned tiles pass; non-TPU backends are exempt (interpret pads)
-    check_tpu_alignment(tpu, tile_width=256, block_batch=8, kernel="x")
-    check_tpu_alignment(_sched(2, "static"), tile_width=40, block_batch=5,
+        check_tpu_alignment(tpu, tile_width=256, full_width=512,
+                            block_batch=5, kernel="lstm_scan")
+    # aligned tiles pass; interpreted backends are exempt (interpret pads)
+    check_tpu_alignment(tpu, tile_width=256, full_width=512, block_batch=8,
                         kernel="x")
+    check_tpu_alignment(_sched(2, "static"), tile_width=40, full_width=80,
+                        block_batch=5, kernel="x")
+
+
+@pytest.mark.parametrize("width", [80, 360, 480])
+def test_tpu_alignment_accepts_whole_width_tile(width):
+    """A tile spanning the whole gate width is legal for Mosaic at any
+    width (the store is static): R=1 on the paper's H=20/120 taggers."""
+    from repro.kernels.ops import check_tpu_alignment
+
+    check_tpu_alignment(KernelSchedule(backend="pallas_tpu"),
+                        tile_width=width, full_width=width, block_batch=8,
+                        kernel="lstm_scan")
+
+
+# ---------------------------------------------------------------------------
+# Backend resolution: "auto" follows the platform JAX traces for
+# ---------------------------------------------------------------------------
+
+
+def test_auto_backend_interprets_on_cpu():
+    from repro.kernels.schedule import resolve_interpret
+
+    assert jax.default_backend() == "cpu"
+    assert KernelSchedule().interpret is True
+    assert resolve_interpret() is True
+    assert KernelSchedule(backend="pallas_tpu").interpret is False
+    assert KernelSchedule(backend="pallas_interpret").interpret is True
+
+
+def test_auto_backend_unknown_platform_raises(monkeypatch):
+    monkeypatch.setattr(jax, "default_backend", lambda: "gpu")
+    with pytest.raises(RuntimeError, match="no Pallas mode"):
+        KernelSchedule().interpret
+    # the explicit backends do not consult the platform
+    assert KernelSchedule(backend="pallas_tpu").interpret is False
+
+
+def test_auto_backend_compiled_on_tpu_is_alignment_checked(monkeypatch):
+    """An "auto" schedule that resolves to compiled Mosaic gets the same
+    alignment check as an explicit pallas_tpu one."""
+    from repro.kernels.ops import check_tpu_alignment
+
+    auto = KernelSchedule(reuse_factor=2)
+    check_tpu_alignment(auto, tile_width=40, full_width=80, block_batch=8,
+                        kernel="lstm_scan")          # cpu: interpreted
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert auto.interpret is False
+    with pytest.raises(ValueError, match="multiple of 128"):
+        check_tpu_alignment(auto, tile_width=40, full_width=80,
+                            block_batch=8, kernel="lstm_scan")
+    check_tpu_alignment(auto, tile_width=80, full_width=80, block_batch=8,
+                        kernel="lstm_scan")
 
 
 def test_tpu_alignment_enforced_at_dispatch():
