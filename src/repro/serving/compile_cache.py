@@ -47,6 +47,8 @@ from functools import lru_cache
 from pathlib import Path
 from typing import Any, Callable, Dict, Optional, Tuple
 
+from repro.serving.spans import tracer
+
 #: bump to invalidate every existing cache entry (serialization layout)
 _FORMAT_VERSION = 1
 
@@ -310,17 +312,18 @@ class CachedExecutor:
         self._compiled: Dict[Tuple, Callable] = {}
 
     def _acquire(self, sig: Tuple, args: Tuple[Any, ...]) -> Callable:
-        meta = {**self._meta, "treedef": sig[0], "leaves": sig[1]}
-        fn = self._cache.load(self._name, meta, self.key)
-        if fn is not None:
-            self._cache.record_warm(self.key)
-        else:
-            t0 = time.perf_counter()
-            fn = self._jitted.lower(*args).compile()
-            self._cache.record_cold(self.key, time.perf_counter() - t0)
-            self._cache.store(self._name, meta, fn, self.key)
-        self._compiled[sig] = fn
-        return fn
+        with tracer()("compile.acquire"):
+            meta = {**self._meta, "treedef": sig[0], "leaves": sig[1]}
+            fn = self._cache.load(self._name, meta, self.key)
+            if fn is not None:
+                self._cache.record_warm(self.key)
+            else:
+                t0 = time.perf_counter()
+                fn = self._jitted.lower(*args).compile()
+                self._cache.record_cold(self.key, time.perf_counter() - t0)
+                self._cache.store(self._name, meta, fn, self.key)
+            self._compiled[sig] = fn
+            return fn
 
     def __call__(self, *args):
         sig = _arg_signature(args)

@@ -43,6 +43,7 @@ from repro.kernels.schedule import (DEFAULT_SCHEDULE_KEY, KernelSchedule,
 from repro.models import rnn_tagger
 from repro.serving.batcher import KeyStats, MicroBatcher, Request, _pad_stack
 from repro.serving.compile_cache import CachedExecutor, CompileCache
+from repro.serving.spans import tracer
 
 RAGGED_POLICIES = ("bucket", "mask")
 
@@ -251,11 +252,15 @@ class RNNServingEngine:
 
     def _predict_key(self, key: str, x: np.ndarray,
                      lengths: Optional[np.ndarray] = None) -> np.ndarray:
+        span = tracer()
         fn = self._infer_cache[self._resolve_default_key(key)]
-        if lengths is None:
-            return np.asarray(fn(self.params, self._put(x)))
-        return np.asarray(fn(self.params, self._put(x),
-                             self._put(np.asarray(lengths, np.int32))))
+        with span("engine.put"):
+            args = (self._put(x),) if lengths is None else (
+                self._put(x), self._put(np.asarray(lengths, np.int32)))
+        with span("engine.dispatch"):
+            y = fn(self.params, *args)
+        with span("engine.fetch"):
+            return np.asarray(y)
 
     def predict(self, x: np.ndarray,
                 schedule: Optional[KernelSchedule] = None,
@@ -263,12 +268,13 @@ class RNNServingEngine:
                 target: Optional[DesignTarget] = None) -> np.ndarray:
         """[b, T, in] -> [b, n_outputs] under the request's schedule (or the
         schedule auto-picked for its ``target``)."""
-        self._check_open()
-        if target is not None and schedule is None:
-            pt = self.schedule_for_target(target)
-            schedule, fp = pt.schedule, fp if fp is not None else pt.fp
-        key = self._ensure_key(*self.resolve(schedule, fp))
-        return self._predict_key(key, x)
+        with tracer()("engine.predict"):
+            self._check_open()
+            if target is not None and schedule is None:
+                pt = self.schedule_for_target(target)
+                schedule, fp = pt.schedule, fp if fp is not None else pt.fp
+            key = self._ensure_key(*self.resolve(schedule, fp))
+            return self._predict_key(key, x)
 
     def predict_ragged(self, xs: List[np.ndarray],
                        schedule: Optional[KernelSchedule] = None,
@@ -379,24 +385,35 @@ class RNNServingEngine:
         recorded per key (compile calls excluded) and reported by
         ``serve_report`` as the ``fast_path`` column.
         """
-        self._check_open()
-        if target is not None and schedule is None:
-            pt = self.schedule_for_target(target)
-            schedule, fp = pt.schedule, fp if fp is not None else pt.fp
-        sched, fpr = self.resolve(schedule, fp)
-        key = self._ensure_key(sched, fpr)   # registers specs for reporting
-        fn = self._one_cache.get(key)
-        if fn is None:
-            fn = self._one_cache[key] = self._make_one_infer(key, sched, fpr)
-        traces_before = self._one_traces.get(key, 0)
-        t0 = time.perf_counter()
-        x1 = (x if isinstance(x, (np.ndarray, jax.Array))
-              else np.asarray(x))[None]
-        out = np.asarray(fn(self.params, self._put(x1)))[0]
-        if self._one_traces.get(key, 0) == traces_before:   # steady state
-            self._one_stats.setdefault(key, KeyStats()).record_one(
-                time.perf_counter() - t0)
-        return out
+        span = tracer()
+        with span("engine.predict_one"):
+            self._check_open()
+            if target is not None and schedule is None:
+                pt = self.schedule_for_target(target)
+                schedule, fp = pt.schedule, fp if fp is not None else pt.fp
+            sched, fpr = self.resolve(schedule, fp)
+            key = self._ensure_key(sched, fpr)  # registers specs for reports
+            fn = self._one_cache.get(key)
+            if fn is None:
+                fn = self._one_cache[key] = self._make_one_infer(key, sched,
+                                                                 fpr)
+            traces_before = self._one_traces.get(key, 0)
+            t0 = time.perf_counter()
+            x1 = (x if isinstance(x, (np.ndarray, jax.Array))
+                  else np.asarray(x))[None]
+            with span("engine.put"):
+                x1 = self._put(x1)
+            with span("engine.dispatch"):
+                y = fn(self.params, x1)
+            with span("engine.fetch"):
+                out = np.asarray(y)[0]
+            if self._one_traces.get(key, 0) == traces_before:  # steady state
+                self._one_stats.setdefault(key, KeyStats()).record_one(
+                    time.perf_counter() - t0)
+            # free the call's device buffers inside the root span, so that
+            # their cost shows in its self time, not between calls
+            del x1, y
+            return out
 
     def one_trace_count(self, key: str) -> int:
         return self._one_traces.get(key, 0)
@@ -477,11 +494,12 @@ class RNNServingEngine:
         max_batch: constant shapes, so mixed-schedule traffic costs at most
         one jit trace per schedule hash.  Zero rows are row-wise inert on
         every backend (verified by the conformance suite)."""
-        xp, b = self._pad_rows(np.asarray(x), key)
-        if lengths is not None and xp.shape[0] != len(lengths):
-            lp = np.zeros((xp.shape[0],), np.int32)
-            lp[:b] = lengths
-            lengths = lp
+        with tracer()("engine.pad"):
+            xp, b = self._pad_rows(np.asarray(x), key)
+            if lengths is not None and xp.shape[0] != len(lengths):
+                lp = np.zeros((xp.shape[0],), np.int32)
+                lp[:b] = lengths
+                lengths = lp
         return self._predict_key(key, xp, lengths)[:b]
 
     def _flush_fn(self, key: str) -> Callable:
@@ -502,7 +520,8 @@ class RNNServingEngine:
               force: bool = False) -> List[Request]:
         """Flush every ready queue (fair round-robin across schedule keys);
         ``force`` also flushes below-threshold leftovers (end of stream)."""
-        return self.batcher.run_all(self._flush_fn, now=now, force=force)
+        with tracer()("engine.flush"):
+            return self.batcher.run_all(self._flush_fn, now=now, force=force)
 
     def serve(self, payloads, schedules=None, fps=None,
               now: Optional[float] = None) -> List[Request]:
