@@ -26,8 +26,9 @@ frameworks do (export/compile ahead of time, load artifacts at serve time):
     directory: serialize to a unique temp file, then atomic
     ``os.replace`` — readers only ever see complete entries.
 
-Per-logical-key cold/warm counters feed the engines' ``serve_report``
-(the ``compile`` column: hit rate + first-request compile seconds).
+Per-logical-key cold/warm/hot counters feed the engines' ``serve_report``
+(the ``compile`` column: hit rate, the share of calls served from memory,
+first-request compile seconds).
 
 :func:`enable_jax_compilation_cache` is the one place entry points turn on
 JAX's own persistent compilation cache.
@@ -121,18 +122,22 @@ class KeyCompileStats:
 
     cold: int = 0                       # fresh lower+compile (one jit trace)
     warm: int = 0                       # served from a deserialized artifact
+    hot: int = 0                        # calls whose executable was in memory
     errors: int = 0                     # load/store failures (fell back)
     quarantined: int = 0                # known-corrupt entries skipped
     first_compile_s: Optional[float] = None
 
     def summary(self) -> Dict[str, float]:
         total = self.cold + self.warm
+        served = total + self.hot
         return {
             "cold": float(self.cold),
             "warm": float(self.warm),
+            "hot": float(self.hot),
             "errors": float(self.errors),
             "quarantined": float(self.quarantined),
             "hit_rate": (self.warm / total) if total else 0.0,
+            "hot_share": (self.hot / served) if served else 0.0,
             "first_compile_s": self.first_compile_s,
         }
 
@@ -281,13 +286,24 @@ class CompileCache:
 
 
 def _arg_signature(args: Tuple[Any, ...]) -> Tuple:
-    """Hashable (shape, dtype) signature over every array leaf, plus the
-    pytree structure — the shape-bucket identity of one executable."""
+    """Hashable signature of one executable's arguments: the pytree
+    structure and each array leaf's ``(shape, dtype)``, as the objects
+    themselves.  Real arrays and ``jax.ShapeDtypeStruct`` avals of one
+    shape and dtype give equal signatures.  Nothing is formatted as a
+    string: this runs on every call."""
     import jax
 
     leaves, treedef = jax.tree_util.tree_flatten(args)
-    return (str(treedef),
-            tuple((tuple(l.shape), str(l.dtype)) for l in leaves))
+    return treedef, tuple((l.shape, l.dtype) for l in leaves)
+
+
+def _signature_meta(sig: Tuple) -> Dict[str, Any]:
+    """A signature's part of a persistent entry's metadata, as strings:
+    the key of the entry's file name and content hash."""
+    treedef, leaves = sig
+    return {"treedef": str(treedef),
+            "leaves": tuple((tuple(shape), str(dtype))
+                            for shape, dtype in leaves)}
 
 
 class CachedExecutor:
@@ -297,7 +313,8 @@ class CachedExecutor:
     Call it exactly like the jit'd function (positional args).  The first
     call with a new signature either loads the serialized executable (warm
     — zero jit traces) or lowers/compiles once (cold — the wrapped
-    function's trace-time side effects run) and stores the artifact.
+    function's trace-time side effects run) and stores the artifact; later
+    calls find it in memory (hot).
     :meth:`warm` does the same from ``jax.ShapeDtypeStruct`` avals without
     executing — the engines' pre-warm path.
     """
@@ -306,6 +323,7 @@ class CachedExecutor:
                  meta: Dict[str, Any], name_hint: Optional[str] = None):
         self._jitted = jitted
         self._cache = cache
+        self._stats = cache.stats(key)
         self.key = key
         self._meta = dict(meta)
         self._name = name_hint if name_hint is not None else key
@@ -313,7 +331,7 @@ class CachedExecutor:
 
     def _acquire(self, sig: Tuple, args: Tuple[Any, ...]) -> Callable:
         with tracer()("compile.acquire"):
-            meta = {**self._meta, "treedef": sig[0], "leaves": sig[1]}
+            meta = {**self._meta, **_signature_meta(sig)}
             fn = self._cache.load(self._name, meta, self.key)
             if fn is not None:
                 self._cache.record_warm(self.key)
@@ -330,6 +348,8 @@ class CachedExecutor:
         fn = self._compiled.get(sig)
         if fn is None:
             fn = self._acquire(sig, args)
+        else:
+            self._stats.hot += 1
         return fn(*args)
 
     def warm(self, *args) -> Dict[str, Any]:

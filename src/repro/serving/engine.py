@@ -106,11 +106,18 @@ class RNNServingEngine:
         if self.device is not None:
             self.params = jax.device_put(self.params, self.device)
 
-    def _put(self, x) -> jax.Array:
-        """An input array on this engine's device."""
-        if not isinstance(x, jax.Array):
-            x = np.asarray(x)
-        return jax.device_put(x, self.device)
+    def _put(self, x):
+        """An input as the engine's executables take it.  A host array stays
+        on the host, C-contiguous in its canonical dtype: the executable's
+        own call copies it to the device.  A ``jax.Array`` on this engine's
+        device passes as it is; any other is moved there."""
+        if isinstance(x, jax.Array):
+            if self.device is not None and x.devices() == {self.device}:
+                return x
+            return jax.device_put(x, self.device)
+        x = np.asarray(x)
+        return np.ascontiguousarray(
+            x, dtype=jax.dtypes.canonicalize_dtype(x.dtype))
 
     # -- schedule resolution -------------------------------------------------
 
@@ -399,10 +406,9 @@ class RNNServingEngine:
                                                                  fpr)
             traces_before = self._one_traces.get(key, 0)
             t0 = time.perf_counter()
-            x1 = (x if isinstance(x, (np.ndarray, jax.Array))
-                  else np.asarray(x))[None]
             with span("engine.put"):
-                x1 = self._put(x1)
+                x1 = self._put((x if isinstance(x, (np.ndarray, jax.Array))
+                                else np.asarray(x))[None])
             with span("engine.dispatch"):
                 y = fn(self.params, x1)
             with span("engine.fetch"):
