@@ -1,15 +1,10 @@
 """What decides ``correct``, and what the metric readers are given.
 
 Every answer that the timed path produced in the window is compared with
-the NumPy float32 reference (``bench/reference.py``) of the same event
-under the same weights.  Each number compared has its limit:
+the reference of the configuration's family (``bench/families/``): the
+family's ``compare`` gives its own checks, each beside its limit (the
+configuration's ``limits``), which come first; every family shares these:
 
-``prob_max_abs_err``            widest gap between a served class
-                                probability and the reference's, over every
-                                answer of the window; its limit is the
-                                configuration's (``limits``), set from
-                                on-chip readings of the program and of its
-                                control as ``PERF.md`` records;
 ``missing_answers``             events attempted and not answered: 0;
 ``executables_without_kernel``  served executables without a compiled
                                 Mosaic kernel (``tpu_custom_call``), on a
@@ -20,7 +15,6 @@ under the same weights.  Each number compared has its limit:
 
 from __future__ import annotations
 
-import math
 from dataclasses import dataclass
 from typing import Callable, Dict, List, Optional
 
@@ -79,16 +73,11 @@ def reference_for(idx: np.ndarray, x: np.ndarray,
     return out
 
 
-def compare(record, ref: Optional[np.ndarray], limits: Dict, *,
-            missing_kernel: int, compiles_in_window: int) -> Dict:
-    if ref is None or not len(record.idx):
-        err = math.inf
-    else:
-        err = float(np.abs(np.asarray(record.answers, np.float32)
-                           - ref[record.idx]).max())
+def compare(record, family_checks: Dict, *, missing_kernel: int,
+            compiles_in_window: int) -> Dict:
+    """The family's checks of ``record``'s answers, then the shared ones."""
     return {
-        "prob_max_abs_err": {"value": err if math.isfinite(err) else None,
-                             "limit": limits["prob_max_abs_err"]},
+        **family_checks,
         "missing_answers": {"value": int(record.failed), "limit": 0},
         "executables_without_kernel": {"value": int(missing_kernel),
                                        "limit": 0},

@@ -7,9 +7,13 @@ process (this is not one of the benchmark's own runs):
   configuration's (``CONTROL_PRECISION``: one bfloat16 pass), on the first
   ``--control-seeds`` of them: the upper reading is the smallest of these.
 
+The numbers read are the checks that the configuration's family names in
+its ``TOLERANCE_CHECKS``: those whose limits the configuration sets.
+
     python3 bench/control.py --workload <name> --seeds 12 --seconds 3
 
-Prints one JSON line per run and a summary line last.
+Prints one JSON line per run and a summary line last: for each of those
+checks, its readings on both sides, its lower and its upper reading.
 """
 
 from __future__ import annotations
@@ -54,7 +58,8 @@ def main(argv=None) -> int:
         devices[0].device_kind]
     control = dataclasses.replace(cell, config={
         **cell.config, "matmul_precision": CONTROL_PRECISION})
-    readings = {"program": [], "control": []}
+    names = cell.family.TOLERANCE_CHECKS
+    readings = {n: {"program": [], "control": []} for n in names}
     for k in range(args.seeds):
         seed = FIRST_SEED + 7919 * k
         sides = [("program", cell)]
@@ -64,15 +69,15 @@ def main(argv=None) -> int:
             res = bench_run.run_cell(c, seed, args.seconds, False,
                                      devices[:cell.chips],
                                      time.perf_counter(), peaks)
-            v = res["checks"]["prob_max_abs_err"]["value"]
-            readings[side].append(v)
+            for n in names:
+                readings[n][side].append(res["checks"][n]["value"])
             print(json.dumps({"side": side, "seed": seed, "correct":
                               res["correct"], "attempted": res["attempted"],
                               "checks": res["checks"]}), flush=True)
-    print(json.dumps({
-        "workload": cell.name, "readings": readings,
-        "lower": max(readings["program"]),
-        "upper": min(readings["control"]) if readings["control"] else None}))
+    print(json.dumps({"workload": cell.name, "checks": {n: {
+        "readings": r, "lower": max(r["program"]),
+        "upper": min(r["control"]) if r["control"] else None}
+        for n, r in readings.items()}}))
     return 0
 
 

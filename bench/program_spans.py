@@ -226,8 +226,7 @@ def main(argv=None) -> int:
     import json
     import shutil
 
-    from bench import drive, run, spec, traffic, weights
-    from bench.events import POOLS
+    from bench import drive, run, spec, traffic
 
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--workload", default="flavor_lstm.single")
@@ -241,20 +240,17 @@ def main(argv=None) -> int:
                         run.ROOT)
 
     import jax
-    import numpy as np
 
     devices = jax.devices()[:cell.chips]
     jax.config.update("jax_compilation_cache_dir",
                       os.path.join(run.CACHE_DIR, "jax"))
-    config = cell.config
+    config, family = cell.config, cell.family
     words = traffic.seed_words(args.seed)
     log_dir = os.path.join(run.TRACE_DIR, cell.name + ".program")
     with jax.default_matmul_precision(config["matmul_precision"]):
-        params = weights.make_params(config["model"], int(words[0]),
-                                     devices[0])
-        x = POOLS[config["events"]](cell.mix["pool"],
-                                    int(words[1]))[0].astype(np.float32)
-        driver = drive.DRIVERS[cell.mix["entry"]](
+        params = family.make_params(config, int(words[0]), devices[0])
+        x = family.make_inputs(config, cell.mix, int(words[1]))
+        driver = family.DRIVERS[cell.mix["entry"]](
             cell, params, x, words, devices, os.path.join(
                 run.CACHE_DIR, f"engine-{config['matmul_precision']}"))
         driver.warm()

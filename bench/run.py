@@ -7,15 +7,16 @@ and its metrics are found by name from ``BENCHMARK.json`` (``bench/spec.py``).
 A run:
 
 1. sets up: JAX on the TPU (it exits non-zero, printing no result, when
-   there is none or fewer chips than the cell asks for), the tagger's
-   weights made on the device from the seed, the event pool from the seed,
+   there is none or fewer chips than the cell asks for), then, through the
+   configuration's family module (``bench/families/<family>.py``), the
+   weights made on the device from the seed, the input pool from the seed,
    the engines, and every shape the cell's traffic uses warmed up;
 2. measures for ``--seconds``; with ``--trace 1`` it then measures for
    ``TRACE_SECONDS`` more under the profiler.  A per-layer metric whose
    ``source`` is the trace or the program's spans reads the traced window,
    any other the untraced one, so that the profiler's cost on the host
    does not reach a host-clock metric;
-3. checks every answer of both windows against the NumPy reference
+3. checks every answer of both windows against the family's reference
    (``bench/check.py``);
 4. prints, as the last line of standard output, one JSON object:
    ``correct``, ``attempted``, ``failed``, ``metrics``, ``device``, with
@@ -111,26 +112,22 @@ def run_cell(cell, seed: int, seconds: float, trace: bool, devices,
 def _run_cell(cell, seed, seconds, trace, devices, t_start, peaks,
               cache_root) -> dict:
     import jax
-    import numpy as np
 
-    from bench import drive, reference, weights
-    from bench.events import POOLS
+    from bench import drive
     from bench.trace import read as read_trace
 
-    config = cell.config
-    model = config["model"]
+    config, family = cell.config, cell.family
     words = traffic.seed_words(seed)
     marks = [("start-up", time.perf_counter())]
-    params = weights.make_params(model, int(words[0]), devices[0])
+    params = family.make_params(config, int(words[0]), devices[0])
     jax.block_until_ready(params)
     marks.append(("weights", time.perf_counter()))
-    x = POOLS[config["events"]](cell.mix["pool"], int(words[1]))[0]
-    x = x.astype(np.float32)
-    marks.append(("event pool", time.perf_counter()))
+    x = family.make_inputs(config, cell.mix, int(words[1]))
+    marks.append(("input pool", time.perf_counter()))
     cache_dir = os.path.join(cache_root,
                              f"engine-{config['matmul_precision']}")
-    driver = drive.DRIVERS[cell.mix["entry"]](cell, params, x, words,
-                                              devices, cache_dir)
+    driver = family.DRIVERS[cell.mix["entry"]](cell, params, x, words,
+                                               devices, cache_dir)
     driver.warm()
     n_exe = len(driver.executables())
     # what set-up made lives on: keep it out of the collector's full
@@ -183,15 +180,14 @@ def _run_cell(cell, seed, seconds, trace, devices, t_start, peaks,
             metrics[m["name"]] = {"value": float(v), "unit": m["unit"]}
 
     # the reference runs once the window has closed and the program's
-    # state is freed, over the pool events that were answered
-    host_params = {k: np.asarray(v) for k, v in params.items()}
-    del driver, exes, params
+    # state is freed, over the pool inputs that were answered
+    del driver, exes
     answered = drive.joined([r for r in (rec, traced) if r is not None])
-    ref = check.reference_for(answered.idx, x, lambda xs:
-                              reference.probabilities(model, host_params, xs))
-    checks = check.compare(answered, ref, config["limits"],
-                           missing_kernel=missing_kernel,
-                           compiles_in_window=compiles)
+    expected = family.reference(config, params, answered, x)
+    del params
+    checks = check.compare(
+        answered, family.compare(answered, expected, config["limits"]),
+        missing_kernel=missing_kernel, compiles_in_window=compiles)
     out = {"correct": check.correct(checks),
            "attempted": int(answered.attempted),
            "failed": int(answered.failed), "metrics": metrics,

@@ -47,7 +47,8 @@ def small_cell(name):
     cell = (spec.resolve(BENCH, name) if name in listed else spec.Cell(
         name=name, chips=1, config=config, traffic=traffic,
         mix=spec.load_json(spec.traffic_file(traffic)),
-        end_to_end=[e2e["setup_s"]], per_layer=[]))
+        end_to_end=[e2e["setup_s"]], per_layer=[],
+        family=spec.family(config["family"])))
     return dataclasses.replace(cell, config=dict(config, impl="xla"),
                                mix=dict(cell.mix, pool=256, warm_calls=2,
                                         **mix))

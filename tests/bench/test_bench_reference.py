@@ -5,7 +5,7 @@ import jax
 import numpy as np
 import pytest
 
-from bench import reference, weights
+from bench import reference, spec, weights
 
 
 def _model(cell, hidden=16, seq_len=7, input_size=5):
@@ -16,12 +16,12 @@ def _model(cell, hidden=16, seq_len=7, input_size=5):
 
 @pytest.mark.parametrize("cell", ["lstm", "gru"])
 def test_reference_matches_the_program_forward(cell):
-    from bench.drive import model_config
     from repro.models import rnn_tagger
 
     model = _model(cell)
-    cfg = model_config({"name": "t", "model": model,
-                        "param_dtype": "float32", "compute_dtype": "float32"})
+    cfg = spec.family("rnn_tagger").model_config(
+        {"name": "t", "model": model, "param_dtype": "float32",
+         "compute_dtype": "float32"})
     params = weights.make_params(model, 5, jax.devices()[0])
     x = np.random.RandomState(1).randn(9, 7, 5).astype(np.float32)
     with jax.default_matmul_precision("highest"):

@@ -8,7 +8,7 @@ import re
 
 import pytest
 
-from bench import drive, spec
+from bench import spec
 
 BENCH = spec.load_benchmark()
 CELLS = [w["name"] for w in BENCH["workloads"]]
@@ -58,7 +58,7 @@ def test_command_and_paths_stay_inside_the_benchmark():
 @pytest.mark.parametrize("name", CELLS)
 def test_cell_resolves_every_file_by_name(name):
     cell = spec.resolve(BENCH, name)
-    assert cell.mix["entry"] in drive.DRIVERS
+    assert cell.mix["entry"] in cell.family.DRIVERS
     assert cell.chips in (1, 4)
     assert {m["name"] for m in cell.end_to_end} >= {"setup_s"}
     assert len(cell.end_to_end) >= 2 and cell.per_layer
@@ -86,8 +86,9 @@ def test_configuration_files_hold_the_paper_sizes(config, build):
     import importlib
 
     want = importlib.import_module(f"repro.configs.{build}").lstm_config()
-    got = drive.model_config(spec.load_json(
-        os.path.join(spec.ROOT, "bench", "configs", f"{config}.json")))
+    file = spec.load_json(
+        os.path.join(spec.ROOT, "bench", "configs", f"{config}.json"))
+    got = spec.family(file["family"]).model_config(file)
     assert got.rnn == want.rnn
     assert (got.param_dtype, got.compute_dtype) == \
         (want.param_dtype, want.compute_dtype)

@@ -11,7 +11,7 @@ import jax
 import numpy as np
 import pytest
 
-from bench import check, drive, spec, weights
+from bench import check, spec, weights
 from bench import program_spans as ps
 from bench import trace as tr
 from repro.kernels.schedule import KernelSchedule
@@ -24,7 +24,8 @@ ROWS = 4
 def make_engine():
     params = weights.make_params(CONFIG["model"], 7, jax.devices()[0])
     return RNNServingEngine(
-        drive.model_config(CONFIG), params, impl="xla", max_batch=ROWS,
+        spec.family(CONFIG["family"]).model_config(CONFIG), params,
+        impl="xla", max_batch=ROWS,
         schedule=KernelSchedule(**dict(CONFIG["schedule"], backend="xla")))
 
 
